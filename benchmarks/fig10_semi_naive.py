@@ -7,9 +7,9 @@ mask is pinned to the target density so each row times exactly one
 operating point of the adaptive dense<->sparse policy; the acceptance bar
 is >= 3x superstep speedup at <= 5% density.
 
-``--sharded`` runs the same sweep on an 8-virtual-device SPMD mesh
-(re-execing itself with ``--xla_force_host_platform_device_count=8`` when
-needed): per-shard compaction, frontier-sized bucket exchanges, and the
+``--sharded`` runs the same sweep on an SPMD mesh over every device (on a
+CPU host, 8 virtual devices: it re-execs itself with
+``--xla_force_host_platform_device_count=8`` when needed): per-shard compaction, frontier-sized bucket exchanges, and the
 collective mode agreement — acceptance bar >= 2x superstep speedup at <= 5%
 density over the sharded dense path.
 
@@ -217,11 +217,17 @@ if __name__ == "__main__":
              "itself with the device-count XLA flag when needed)",
     )
     ns = parser.parse_args()
+    import jax
+
     flags = os.environ.get("XLA_FLAGS", "")
-    if ns.sharded and "xla_force_host_platform_device_count" not in flags:
-        # The device-count flag must be set before jax initializes: re-exec
-        # with the --json operand absolutized so the snapshot still lands in
-        # the caller's cwd (the child runs with cwd=_ROOT).
+    if (ns.sharded and jax.default_backend() == "cpu"
+            and "xla_force_host_platform_device_count" not in flags):
+        # A CPU host needs virtual devices, and the device-count flag must
+        # be set before jax initializes: re-exec with the --json operand
+        # absolutized so the snapshot still lands in the caller's cwd (the
+        # child runs with cwd=_ROOT).  An accelerator host shards over its
+        # real devices in this process: a child could not reach them while
+        # this process holds them.
         from repro.launch.mesh import virtual_device_env
 
         argv = ["--sharded"]

@@ -9,7 +9,8 @@ chunks), plus a larger-than-budget row where a deliberately tiny
 class that simply cannot hold its EDB in device memory, completing on the
 streaming path and matching the in-memory answer exactly.
 
-``--sharded`` re-execs onto an 8-virtual-device SPMD mesh and times the
+``--sharded`` runs on an SPMD mesh over every device (a CPU host re-execs
+onto 8 virtual devices) and times the
 explicit key-hash bucket all-to-all lowering against the implicit GSPMD
 partitioning of the same row-table fixpoint (informational rows: on
 virtual CPU devices the collectives are memcpys, so the interconnect-
@@ -176,8 +177,17 @@ if __name__ == "__main__":
              "device-count XLA flag when needed)",
     )
     ns = parser.parse_args()
+    import jax
+
     flags = os.environ.get("XLA_FLAGS", "")
-    if ns.sharded and "xla_force_host_platform_device_count" not in flags:
+    if (ns.sharded and jax.default_backend() == "cpu"
+            and "xla_force_host_platform_device_count" not in flags):
+        # A CPU host needs virtual devices, and the device-count flag must
+        # be set before jax initializes: re-exec with the --json operand
+        # absolutized so the snapshot still lands in the caller's cwd (the
+        # child runs with cwd=_ROOT).  An accelerator host shards over its
+        # real devices in this process: a child could not reach them while
+        # this process holds them.
         from repro.launch.mesh import virtual_device_env
 
         argv = ["--sharded"]

@@ -84,8 +84,10 @@ def _build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     from benchmarks._json import parse_lines, write_doc
+    from repro.launch.compile_cache import enable_compile_cache
 
     ns = _build_parser().parse_args(argv)
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     rows = []

@@ -75,6 +75,7 @@ from repro.core.fixpoint import (
     FixpointResult,
     HostFixpointDriver,
     device_fixpoint,
+    jit_hoisted,
 )
 from repro.core.hardware import MeshSpec, TPU_V5E, HardwareSpec
 from repro.core.monoid import MonoidError, get_monoid
@@ -407,6 +408,9 @@ class _Ctx:
     row_cap: int = 0
     row_edb: Mapping[str, Dict[str, Any]] = field(default_factory=dict)
     overflow: List[Any] = field(default_factory=list)
+    # Joins that read a row-table EDB slab get one pair slot per slab row
+    # (off when the caller pins ``row_cap``; see ``_pair_cap``).
+    edb_join_rows: bool = False
     # Explicit sharded exchanges: the planner's per-predicate connector
     # selection + receiver caps, the head predicate of the firing rule (the
     # selection key), and the mesh/data-axes the shard_map lowering targets.
@@ -766,7 +770,7 @@ def _groupby_rows_exchange(op: algebra.GroupBy, child: _Rows, ctx: _Ctx):
     return _Rows(tuple(op.keys), g_ids, g_valid, {op.out_col: g_val})
 
 
-def _join_rows_exchange(l: _Rows, r: _Rows, keys, ctx: _Ctx):
+def _join_rows_exchange(l: _Rows, r: _Rows, keys, ctx: _Ctx, pair_cap: int):
     """Hash-partitioned sort-merge join inside ``shard_map``: both slabs
     split ``1/S`` per shard, rows ship to ``hash(shared-code) % S`` through
     the bucket all-to-all, each owner joins exactly its key partition (the
@@ -791,7 +795,7 @@ def _join_rows_exchange(l: _Rows, r: _Rows, keys, ctx: _Ctx):
     out_dims = l.dims + tuple(d for d in r.dims if d not in l.dims)
     ecap = int(ctx.exchange_caps.get(ctx.exchange_target, 0)) \
         or max(lcap, rcap)
-    pair_cap = -(-max(ctx.row_cap, 1) // n_shards)
+    shard_pair_cap = -(-pair_cap // n_shards)
 
     def pack_side(rows: _Rows, cap: int):
         pad = (-cap) % n_shards
@@ -818,7 +822,7 @@ def _join_rows_exchange(l: _Rows, r: _Rows, keys, ctx: _Ctx):
             r_t, rv, n_shards, ecap, axes,
         )
         li, ri, valid, of_j = join_row_codes(
-            lx["codes"], lvx, rx["codes"], rvx, pair_cap
+            lx["codes"], lvx, rx["codes"], rvx, shard_pair_cap
         )
         l2 = _Rows(l.dims, lx["ids"], lvx, lx["cols"])
         r2 = _Rows(r.dims, rx["ids"], rvx, rx["cols"])
@@ -857,11 +861,29 @@ def _join_rows_exchange(l: _Rows, r: _Rows, keys, ctx: _Ctx):
     return _Rows(out_dims, g_ids, g_valid, g_cols)
 
 
-def _join_rows(l: _Rows, r: _Rows, keys, ctx: _Ctx) -> _Rows:
-    """Sort-merge equi-join on the shared dims' row codes; pairs expand
-    into the plan's intermediate capacity (overflow-flagged)."""
+def _pair_cap(op: algebra.LogicalOp, ctx: _Ctx) -> int:
+    """A row join's pair capacity: the plan's intermediate cap, raised to
+    the slab's row count when the join reads a row-table EDB slab (a join
+    against a key-unique side yields one pair per slab row)."""
 
-    out = _join_rows_exchange(l, r, keys, ctx)
+    cap = max(ctx.row_cap, 1)
+    stack = [op] if ctx.edb_join_rows else []
+    while stack:
+        node = stack.pop()
+        if isinstance(node, algebra.ScanEDB) and node.relation in ctx.row_edb:
+            cap = max(cap, int(ctx.row_edb[node.relation]["ids"].shape[0]))
+        stack.extend(node.children())
+    return cap
+
+
+def _join_rows(l: _Rows, r: _Rows, keys, ctx: _Ctx,
+               pair_cap: Optional[int] = None) -> _Rows:
+    """Sort-merge equi-join on the shared dims' row codes; pairs expand
+    into ``pair_cap`` slots (default: the plan's intermediate capacity),
+    overflow-flagged."""
+
+    pair_cap = max(ctx.row_cap, 1) if pair_cap is None else pair_cap
+    out = _join_rows_exchange(l, r, keys, ctx, pair_cap)
     if out is not None:
         return out
     n = ctx.n
@@ -869,7 +891,7 @@ def _join_rows(l: _Rows, r: _Rows, keys, ctx: _Ctx) -> _Rows:
     out_dims = l.dims + tuple(d for d in r.dims if d not in l.dims)
     li, ri, valid, ov = join_row_codes(
         _codes_for(l, shared, n), l.valid,
-        _codes_for(r, shared, n), r.valid, max(ctx.row_cap, 1),
+        _codes_for(r, shared, n), r.valid, pair_cap,
     )
     ctx.overflow.append(ov)
     valid = _residual_valid(l, r, keys, li, ri, valid)
@@ -880,7 +902,7 @@ def _join_rows(l: _Rows, r: _Rows, keys, ctx: _Ctx) -> _Rows:
         else:
             id_cols.append(r.ids[:, r.dims.index(d)][ri])
     ids = jnp.stack(id_cols, axis=-1) if id_cols else \
-        jnp.zeros((max(ctx.row_cap, 1), 0), jnp.int32)
+        jnp.zeros((pair_cap, 0), jnp.int32)
     cols: Dict[str, Any] = {}
     for c, g in l.cols.items():
         if c not in out_dims:
@@ -1060,14 +1082,14 @@ def _eval_inner(op: algebra.LogicalOp, ctx: _Ctx):
             _eval(op.left, ctx), _eval(op.right, ctx), ctx
         )
         if rowmode:
-            return _join_rows(l, r, op.keys, ctx)
+            return _join_rows(l, r, op.keys, ctx, _pair_cap(op, ctx))
         return _join(l, r, op.keys, n)
     if isinstance(op, algebra.Cross):
         l, r, rowmode = _coerce_pair(
             _eval(op.left, ctx), _eval(op.right, ctx), ctx
         )
         if rowmode:
-            return _join_rows(l, r, (), ctx)
+            return _join_rows(l, r, (), ctx, _pair_cap(op, ctx))
         return _join(l, r, (), n)
     if isinstance(op, algebra.AntiJoin):
         l, r, rowmode = _coerce_pair(
@@ -1547,6 +1569,7 @@ class GenericExecutable:
             row_caps=self.row_caps,
             row_cap=self.row_cap,
             row_edb=self.row_edb,
+            edb_join_rows=self._compile_kwargs.get("row_cap") is None,
             exchanges=dict(getattr(self.plan, "exchanges", {}) or {}),
             exchange_caps=dict(getattr(self.plan, "exchange_caps", {}) or {}),
             mesh=self.mesh,
@@ -1966,7 +1989,7 @@ class GenericExecutable:
                 )
                 return out_acc, of
 
-            fn = jax.jit(fire)
+            fn = jit_hoisted(fire)
             self._step_cache[key] = fn
         return fn
 
@@ -1991,7 +2014,7 @@ class GenericExecutable:
                     phase, ctx, state, _dfs, accs, of_chunks
                 )
 
-            fn = jax.jit(finish)
+            fn = jit_hoisted(finish)
             self._step_cache[key] = fn
         return fn
 
@@ -2110,9 +2133,11 @@ class GenericExecutable:
         """The memoized jitted step of one fixpoint phase, as
         ``step(state, materialized, param_grids, j)``.  Everything that
         changes between requests is an argument; loop-invariant EDB grids
-        stay closure constants (cached device-resident).  ``batched=True``
-        vmaps the step over a leading query axis of (state, materialized,
-        params) with ``j`` broadcast — one fixpoint serving k queries."""
+        are closed over, and :class:`~repro.core.fixpoint.jit_hoisted`
+        passes them to the compiled step as device-resident arguments.
+        ``batched=True`` vmaps the step over a leading query axis of (state,
+        materialized, params) with ``j`` broadcast — one fixpoint serving k
+        queries."""
 
         key = ("batched" if batched else "seq", phase.index)
         fn = self._step_cache.get(key)
@@ -2123,7 +2148,7 @@ class GenericExecutable:
                     _phase, materialized, relations=rels
                 )(state, j)
 
-            fn = jax.jit(
+            fn = jit_hoisted(
                 jax.vmap(step_one, in_axes=(0, 0, 0, None))
                 if batched else step_one
             )
@@ -2154,7 +2179,7 @@ class GenericExecutable:
                 ))
                 return state, mat
 
-            fn = jax.jit(jax.vmap(one))
+            fn = jit_hoisted(jax.vmap(one))
         elif kind == "init":
             def one(state, mat, params, _phase=phase):
                 rels = self._bind_params(params)
@@ -2168,7 +2193,7 @@ class GenericExecutable:
                         out[pred] = self._init_entry(entry)
                 return out
 
-            fn = jax.jit(jax.vmap(one))
+            fn = jit_hoisted(jax.vmap(one))
         elif kind == "finals":
             def one(state, mat, params, j, _phase=phase):
                 rels = self._bind_params(params)
@@ -2183,14 +2208,14 @@ class GenericExecutable:
                 ))
                 return m
 
-            fn = jax.jit(jax.vmap(one, in_axes=(0, 0, 0, None)))
+            fn = jit_hoisted(jax.vmap(one, in_axes=(0, 0, 0, None)))
         elif kind == "conv":
             conv_one = self._phase_converged(phase)
 
             def one(prev, new, _c=conv_one):
                 return jnp.all(jax.vmap(_c)(prev, new))
 
-            fn = jax.jit(one)
+            fn = jit_hoisted(one)
         else:
             raise ExecutorError(f"unknown batched stage {kind!r}")
         self._step_cache[key] = fn
@@ -2334,7 +2359,13 @@ class GenericExecutable:
                 state[pred] = jax.tree_util.tree_map(
                     place, self._init_entry(entry)
                 )
-        return jax.jit(self._phase_step(phase, materialized)), state
+        return jit_hoisted(self._phase_step(phase, materialized)), state
+
+    def compiled_steps(self) -> List[Any]:
+        """The compiled programs of every jitted step this executable has
+        run (``jax.stages.Compiled``): what ran on the device."""
+
+        return [c for fn in self._step_cache.values() for c in fn.compiled()]
 
     def _init_entry(self, out: Dict[str, Any]) -> Dict[str, Any]:
         """Promote a materialized out into a carried entry: everything is
@@ -3446,9 +3477,11 @@ def build_pregel_steps(prog, graph, plan, mesh,
         dst_p = np.where(pad_mask, 0, dst_p)
 
         spec1 = P(batch_axes)
-        src_arr = jnp.asarray(src_p.reshape(-1))
-        dst_arr = jnp.asarray(dst_p.reshape(-1))
-        pad_arr = jnp.asarray(pad_mask.reshape(-1))
+        # Each shard's edge slab lives on its own device from the start.
+        src_arr, dst_arr, pad_arr = (
+            jax.device_put(a.reshape(-1), NamedSharding(mesh, spec1))
+            for a in (src_p, dst_p, pad_mask)
+        )
 
         vdata = jax.device_put(
             graph.vertex_data, NamedSharding(mesh, spec1)
@@ -3581,7 +3614,7 @@ def build_pregel_steps(prog, graph, plan, mesh,
                 return wrapped(state, active, src_arr, dst_arr, pad_arr,
                                edata, j)
 
-            return jax.jit(step)
+            return jit_hoisted(step)
     else:
         def superstep(carry, j):
             state, active = carry
@@ -3617,7 +3650,7 @@ def build_pregel_steps(prog, graph, plan, mesh,
                 inbox, got = fused_got_exchange(ex, payload, valid, op)
                 return _apply_and_merge(prog, j, state, inbox, got)
 
-            return jax.jit(step)
+            return jit_hoisted(step)
 
         shard_count_fn = None
         slab_cap = graph.n_edges
@@ -3708,12 +3741,12 @@ def build_imru_step(task, records, plan, mesh, mesh_spec):
             in_specs=in_specs, out_specs=P(),
             check_rep=False,
         )
-        step = jax.jit(lambda model, j: step_inner(records, model, j))
+        step = jit_hoisted(lambda model, j: step_inner(records, model, j))
     else:
         def step_fn(model, j):
             partial = local_partial(records, model)
             return task.update(j, model, partial)
 
-        step = jax.jit(step_fn)
+        step = jit_hoisted(step_fn)
 
     return step, records

@@ -6,8 +6,9 @@ no new facts are derived):
 
 * :func:`device_fixpoint` — the whole loop lives on device as a
   ``lax.while_loop`` whose carried state is the recursive-predicate frontier
-  (model/vertex/send arrays).  Loop-invariant EDB relations are captured as
-  closure constants, i.e. cached device-resident across iterations — the
+  (model/vertex/send arrays).  Loop-invariant EDB relations are closed
+  over and passed to the compiled loop as device-resident arguments
+  (:class:`jit_hoisted`), read by every iteration without moving — the
   paper's HaLoop-style "loop-invariant caching", which is what let Hyracks
   beat Hadoop by an order of magnitude in §5.2.
 
@@ -33,6 +34,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 __all__ = [
@@ -40,9 +42,70 @@ __all__ = [
     "device_fixpoint",
     "HostFixpointDriver",
     "DriverConfig",
+    "jit_hoisted",
 ]
 
 logger = logging.getLogger(__name__)
+
+
+class jit_hoisted:
+    """``jax.jit`` that passes the arrays ``fn`` closes over as arguments.
+
+    A plain ``jax.jit`` embeds every array a function closes over in the
+    compiled program as a constant: a 67M-edge list or a 4 GiB record set
+    would travel inside the HLO, and XLA constant-folds whole sorts of it
+    at compile time.  Steps here close over their loop-invariant EDB by
+    design, so each input signature is traced once with
+    ``jax.make_jaxpr``, the closed-over arrays (the jaxpr's consts) are
+    lifted out, and the jitted program takes them as ordinary device
+    arguments — placed once, resident across calls.  ``lower`` mirrors
+    ``jax.jit(fn).lower`` for inspecting the compiled step, and
+    ``compiled`` returns the executables the calls so far ran."""
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self._traced: dict = {}
+
+    def _entry(self, args):
+        flat, tree = jax.tree_util.tree_flatten(args)
+        key = (tree, tuple(jax.typeof(a) for a in flat))
+        entry = self._traced.get(key)
+        if entry is None:
+            closed, out_shape = jax.make_jaxpr(
+                self.fn, return_shape=True
+            )(*args)
+            jaxpr = closed.jaxpr
+            run = jax.jit(
+                lambda consts, *xs: jax.core.eval_jaxpr(jaxpr, consts, *xs)
+            )
+            consts = [jax.device_put(c) if isinstance(c, np.ndarray) else c
+                      for c in closed.consts]
+            specs = [jax.ShapeDtypeStruct(
+                t.shape, t.dtype, weak_type=t.weak_type,
+                sharding=a.sharding if isinstance(a, jax.Array)
+                and not isinstance(a, jax.core.Tracer) and a.committed
+                else None,
+            ) for a, t in zip(flat, key[1])]
+            entry = (run, consts, jax.tree_util.tree_structure(out_shape),
+                     specs)
+            self._traced[key] = entry
+        return entry, flat
+
+    def __call__(self, *args):
+        (run, consts, out_tree, _), flat = self._entry(args)
+        return jax.tree_util.tree_unflatten(out_tree, run(consts, *flat))
+
+    def lower(self, *args):
+        (run, consts, _, _), flat = self._entry(args)
+        return run.lower(consts, *flat)
+
+    def compiled(self) -> list:
+        """One ``jax.stages.Compiled`` per input signature traced so far:
+        the executables the calls ran (JAX's compile caches return them,
+        so nothing is compiled again)."""
+
+        return [run.lower(consts, *specs).compile()
+                for run, consts, _, specs in self._traced.values()]
 
 
 @dataclass
@@ -93,8 +156,9 @@ def device_fixpoint(
         return new_state, j + 1, done
 
     t0 = time.perf_counter()
-    fn = jax.jit(
-        lambda s: lax.while_loop(cond, step, (s, jnp.int32(0), jnp.bool_(False)))
+    fn = jit_hoisted(
+        lambda s: lax.while_loop(
+            cond, step, (s, jnp.int32(0), jnp.bool_(False)))
     )
     state, iters, done = fn(init_state)
     state = jax.block_until_ready(state)
@@ -109,6 +173,29 @@ def device_fixpoint(
 # ---------------------------------------------------------------------------
 # Host driver: checkpointing, fault tolerance, elasticity, stragglers
 # ---------------------------------------------------------------------------
+
+
+# XLA status codes of errors that replaying the iteration would raise again.
+_DETERMINISTIC_STATUS = (
+    "INVALID_ARGUMENT", "UNIMPLEMENTED", "RESOURCE_EXHAUSTED",
+    "FAILED_PRECONDITION",
+)
+
+
+def _replayable(exc: Exception) -> bool:
+    """Whether restore-and-replay can get past ``exc``: a device or host
+    failure (a ``RuntimeError``, which injected crashes are too).  Tracing,
+    lowering and compile errors, and running out of device memory, recur
+    on every replay, so they surface at once instead of being retried."""
+
+    if not isinstance(exc, RuntimeError) or isinstance(
+            exc, (NotImplementedError, RecursionError)):
+        return False
+    if isinstance(exc, jax.errors.JaxRuntimeError):
+        msg = str(exc)
+        return not (msg.startswith(_DETERMINISTIC_STATUS)
+                    or "compil" in msg.lower())
+    return True
 
 
 @dataclass
@@ -135,8 +222,10 @@ class HostFixpointDriver:
     * optional ``save(state, j)`` / ``restore() -> (state, j)`` hooks, wired
       to :mod:`repro.checkpoint` by the launchers.
 
-    Failure handling: any exception inside ``step`` triggers restore from the
-    last checkpoint and replay (at-least-once, idempotent because iterations
+    Failure handling: a device or host failure inside ``step`` triggers
+    restore from the last checkpoint and replay (compile and programming
+    errors are deterministic and re-raise at once — see
+    :func:`_replayable`; at-least-once, idempotent because iterations
     are pure functions of state — the Datalog semantics guarantee exactly the
     paper's re-execution story: "the logic for incremental evaluation and
     re-execution in the face of failures" lives below the user program).
@@ -213,6 +302,8 @@ class HostFixpointDriver:
                 new_state = step_fn(state, j)
                 new_state = jax.block_until_ready(new_state)
             except Exception as exc:  # noqa: BLE001 — FT boundary
+                if not _replayable(exc):
+                    raise
                 self.restarts += 1
                 if self.restarts > cfg.max_restarts or self.restore is None:
                     raise

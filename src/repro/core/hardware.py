@@ -26,7 +26,8 @@ import math
 from dataclasses import dataclass, field
 from typing import Dict, Mapping, Optional, Tuple
 
-__all__ = ["HardwareSpec", "MeshSpec", "CollectiveCost", "TPU_V5E"]
+__all__ = ["HardwareSpec", "MeshSpec", "CollectiveCost", "TPU_V5E",
+           "DEVICE_KINDS", "hardware_for"]
 
 
 @dataclass(frozen=True)
@@ -53,6 +54,26 @@ class HardwareSpec:
 
 
 TPU_V5E = HardwareSpec()
+
+# ``jax.Device.device_kind`` -> the spec whose peaks hold on that chip.  The
+# planner prices every plan with these numbers, so a chip not listed here is
+# an error, never a default.  Only kinds a chip has reported are listed (a
+# v5e reports itself as "TPU v5 lite").
+DEVICE_KINDS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of a device kind in :data:`DEVICE_KINDS`."""
+
+    spec = DEVICE_KINDS.get(device_kind)
+    if spec is None:
+        raise ValueError(
+            f"device kind {device_kind!r} has no hardware spec; known: "
+            f"{sorted(DEVICE_KINDS)}"
+        )
+    return spec
 
 
 @dataclass(frozen=True)

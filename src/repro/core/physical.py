@@ -45,6 +45,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.core.monoid import (
@@ -144,15 +145,6 @@ def _generic_combine(
 # ---------------------------------------------------------------------------
 
 
-def _named_axis_size(axis: str) -> int:
-    """``lax.axis_size`` with a fallback for JAX versions that predate it:
-    ``psum`` of a static 1 over the axis constant-folds to the axis size."""
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def _axes_present(axis_names: Sequence[str]) -> Tuple[str, ...]:
     """Filter axis names to those bound in the current shard_map context."""
 
@@ -177,7 +169,7 @@ def kary_tree_psum(x: jax.Array, axis: str, k: int = 4) -> jax.Array:
     payloads over high-latency (cross-pod) links.
     """
 
-    n = _named_axis_size(axis)
+    n = lax.axis_size(axis)
     if n == 1:
         return x
     idx = lax.axis_index(axis)
@@ -259,7 +251,7 @@ def psum_tree(x: jax.Array, schedule: ReduceSchedule,
 def _axes_size(axes: Tuple[str, ...]) -> int:
     n = 1
     for a in axes:
-        n *= _named_axis_size(a)
+        n *= lax.axis_size(a)
     return n
 
 
@@ -337,16 +329,17 @@ def segment_combine_sorted(
 
     Requires ``segment_ids`` sorted ascending; reduces consecutive runs.
     On TPU this dispatches to the Pallas kernel in
-    :mod:`repro.kernels.segment_combine` (banded one-hot matmuls with
-    scalar-prefetched band skipping); elsewhere it lowers to
+    :mod:`repro.kernels.segment_combine` (a scalar-prefetched work list of
+    the (output tile, edge block) pairs whose id bands meet); elsewhere it
+    lowers to
     ``jax.ops.segment_*`` with ``indices_are_sorted=True`` so XLA can use
     the cheap one-pass algorithm (the paper's pre-clustered group-by
     exploiting the order property).
 
     ``edge_active`` (optional bool[E]) is the semi-naive delta-frontier
     mask: rows outside the frontier are excluded from the combine, and the
-    kernel path skips fully-inactive edge blocks outright via its
-    scalar-prefetched active-block bitmap.  Empty segments differ by path
+    kernel path neither fetches nor computes edge blocks with no active
+    row.  Empty segments differ by path
     (kernel: combine identity mapped to 0; XLA max/min: ±inf; generic
     monoids: the identity row) — Pregel callers gate them behind the
     ``got``-a-message mask either way.
@@ -642,7 +635,7 @@ def dense_psum_exchange(
 def _linear_shard_index(axes: Tuple[str, ...]) -> jax.Array:
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * _named_axis_size(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -748,8 +741,8 @@ def _sparse_exchange(
         # Receiver merges pre-sorted runs: sorting nearly-sorted ids is the
         # merge; then a sorted segment reduce (the "merging connector").
         # Empty bucket slots (id -1) are passed as the receiver-side frontier
-        # mask: on TPU the Pallas combiner's active-block bitmap skips slab
-        # blocks made entirely of padding, so receiver compute also scales
+        # mask: on TPU the Pallas combiner skips slab blocks made entirely
+        # of padding, so receiver compute also scales
         # with the frontier, not with n_shards * bucket_cap.
         order = jnp.argsort(local)
         local_s, vals_s = local[order], flat_vals[order]
@@ -774,8 +767,8 @@ def merging_exchange(dst_ids, payload, n_vertices, axes,
 
     ``edge_mask`` (the frontier-masked path) excludes inactive edges from
     the combine.  Single-shard, the mask reaches the receiver combine — on
-    TPU that is the Pallas ``segment_combine`` kernel, whose active-block
-    bitmap skips fully-inactive edge blocks.  Sharded, masked rows are
+    TPU that is the Pallas ``segment_combine`` kernel, which skips edge
+    blocks with no active row.  Sharded, masked rows are
     dropped earlier still, at sender-side bucket packing, so they never
     travel the all_to_all."""
 
@@ -822,7 +815,7 @@ def hash_sort_exchange(dst_ids, payload, n_vertices, axes,
 # same code (the all-max id tuple when domain**k == 2**32): the sort places
 # valid rows first among equal keys, so the valid region is always a prefix
 # of length ``n_valid`` and membership tests stay exact.
-_ROW_SENTINEL = jnp.uint32(0xFFFFFFFF)
+_ROW_SENTINEL = np.uint32(0xFFFFFFFF)
 
 
 def row_codes(ids: jax.Array, n: int) -> jax.Array:

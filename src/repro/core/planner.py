@@ -391,7 +391,9 @@ _ROW_MIN_CELLS = 1 << 21
 _ROW_EST_FACTOR = 16
 # Row-table slab capacities: 8x estimate headroom rounded to a power of
 # two, never above _ROW_CAP_MAX (the lossless overflow fallback catches
-# underestimates); intermediates get 4x the largest predicate slab.
+# underestimates); intermediates get 4x the largest predicate slab.  An EDB
+# slab's row count is exact, so it always gets room for every row (the
+# executor sizes a join that reads it to match: see ``_pair_cap``).
 _ROW_CAP_MAX = 1 << 20
 _ROW_INTER_CAP_MAX = 1 << 22
 # Explicit-exchange selection (see docs/optimizations.md "Out-of-core
@@ -413,6 +415,7 @@ def _select_storage(
     domain: int,
     predicates: Mapping[str, Tuple[int, float]],
     forced: Optional[Mapping[str, str]],
+    exact: Sequence[str] = (),
 ) -> Tuple[Dict[str, str], Dict[str, int]]:
     storage: Dict[str, str] = {}
     row_caps: Dict[str, int] = {}
@@ -443,6 +446,8 @@ def _select_storage(
                 # whole domain grid has cells (small forced-row domains
                 # become overflow-free).
                 cap = min(cap, _next_pow2(int(cells)))
+            if pred in exact:
+                cap = max(cap, _next_pow2(int(est)))
             row_caps[pred] = cap
     return storage, row_caps
 
@@ -517,7 +522,7 @@ def plan_program(
     """
 
     pred_storage, row_caps = _select_storage(
-        domain, predicates or {}, storage
+        domain, predicates or {}, storage, exact=edb
     )
     row_preds = sorted(p for p, s in pred_storage.items() if s == "row-table")
     if row_preds:

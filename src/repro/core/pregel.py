@@ -50,6 +50,7 @@ from repro.core.fixpoint import (
     FixpointResult,
     HostFixpointDriver,
     device_fixpoint,
+    jit_hoisted,
 )
 from repro.core.hardware import MeshSpec, TPU_V5E, HardwareSpec
 from repro.core.listings import pregel_program
@@ -147,11 +148,12 @@ class PregelExecutable:
 
     @property
     def jitted_superstep(self) -> Callable:
-        """The dense superstep under ``jax.jit`` (cached) — host-driver and
+        """The dense superstep under :class:`~repro.core.fixpoint.
+        jit_hoisted` (cached, graph arrays as arguments) — host-driver and
         adaptive runs must not fall back to op-by-op eager dispatch."""
 
         if self._jit_superstep is None:
-            self._jit_superstep = jax.jit(self.superstep)
+            self._jit_superstep = jit_hoisted(self.superstep)
         return self._jit_superstep
 
     def _place_carry(self, carry: Any) -> Any:
@@ -189,7 +191,7 @@ class PregelExecutable:
 
         if self._edge_count_fn is None:
             src = self.graph.src
-            self._edge_count_fn = jax.jit(
+            self._edge_count_fn = jit_hoisted(
                 lambda a: jnp.sum(jnp.take(a, src).astype(jnp.int32))
             )
         return int(self._edge_count_fn(active))

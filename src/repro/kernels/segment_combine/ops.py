@@ -35,15 +35,14 @@ def kernel_eligible(
     narrowed by the f32 accumulator and stay on the XLA path; such callers
     can still opt in explicitly with ``use_kernel=True``.
 
-    ``op`` must name a hardware fast path (sum/max/min — either directly
-    or as a registered monoid's ``kernel_op``): the banded-matmul kernel
-    only implements those three combines, so every other monoid falls back
-    to the generic XLA monoid path regardless of dtype/backend."""
+    Eligibility by op: the kernel implements sum, max and min, and every
+    registered monoid whose ``kernel_op`` names one of them.  A monoid with
+    no ``kernel_op`` (argmin, topk, logsumexp, ...) never reaches the
+    kernel on any backend; the planner records that choice as the
+    ``combine-monoid(<op>, ..., xla-generic)`` plan note."""
 
-    if op not in _FAST_OPS:
-        monoid = get_monoid(op)
-        if monoid.kernel_op is None:
-            return False
+    if op not in _FAST_OPS and get_monoid(op).kernel_op is None:
+        return False
     return (
         jax.default_backend() == "tpu" or bool(interpret)
     ) and values.dtype in (jnp.float32, jnp.bfloat16)
@@ -61,7 +60,7 @@ def segment_combine(
 ) -> jax.Array:
     """``edge_active`` (optional bool[E]) is the delta-frontier mask: rows
     outside the frontier are excluded from the combine, and the Pallas path
-    skips fully-inactive edge blocks via a scalar-prefetched bitmap.  The
+    neither fetches nor computes edge blocks with no active row.  The
     sharded sparse connectors reuse the same mask for their receiver slabs
     (empty all-to-all bucket slots), so receiver-side combine work also
     scales with the frontier.  Auto-dispatch (``use_kernel=None``) follows

@@ -265,6 +265,9 @@ def main() -> int:
     if args.all:
         return 1 if _run_all(mesh_kinds, args.force) else 0
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     overrides: Dict[str, Any] = {}
     for ov in args.override:
         k, v = ov.split("=", 1)

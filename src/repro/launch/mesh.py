@@ -15,25 +15,12 @@ import os
 
 import jax
 
-__all__ = ["make_compat_mesh", "make_data_mesh", "make_production_mesh",
+__all__ = ["make_data_mesh", "make_production_mesh",
            "mesh_spec_of", "virtual_device_env",
            "SINGLE_POD_AXES", "MULTI_POD_AXES"]
 
 SINGLE_POD_AXES = (("data", 16), ("model", 16))
 MULTI_POD_AXES = (("pod", 2), ("data", 16), ("model", 16))
-
-
-def make_compat_mesh(shape, axes):
-    """``jax.make_mesh`` across JAX versions: ``axis_types`` (and the
-    ``AxisType`` enum) only exist on newer releases; older ones default every
-    axis to auto sharding, which is exactly what we pass anyway."""
-
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-    )
 
 
 def make_data_mesh(n_data: int = 0):
@@ -44,7 +31,9 @@ def make_data_mesh(n_data: int = 0):
 
     if n_data <= 0:
         n_data = len(jax.devices())
-    return make_compat_mesh((n_data,), ("data",))
+    return jax.make_mesh(
+        (n_data,), ("data",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 def virtual_device_env(n: int = 8, base_env=None) -> dict:
@@ -68,7 +57,9 @@ def virtual_device_env(n: int = 8, base_env=None) -> dict:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def mesh_spec_of(mesh) -> "MeshSpec":

@@ -15,10 +15,11 @@ import jax.numpy as jnp
 
 def main() -> None:
     results = {}
-    from repro.launch.mesh import make_compat_mesh  # AxisType version shim
-
-    mesh2 = make_compat_mesh((4, 2), ("data", "model"))
-    mesh3 = make_compat_mesh((2, 2, 2), ("pod", "data", "model"))
+    auto = jax.sharding.AxisType.Auto
+    mesh2 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(auto,) * 2)
+    mesh3 = jax.make_mesh(
+        (2, 2, 2), ("pod", "data", "model"), axis_types=(auto,) * 3
+    )
 
     # --- IMRU: every reduce schedule reaches the same fixpoint -------------
     from repro.core.imru import IMRUTask, compile_imru

@@ -160,3 +160,29 @@ def test_segment_combine_property_sorted_sum(n_seg, n_rows, f, seed):
     for i, s in enumerate(ids):
         dense[s] += vals[i]
     np.testing.assert_allclose(np.asarray(out), dense, atol=1e-4)
+
+
+@pytest.mark.parametrize("op", ["sum", "max", "min"])
+@pytest.mark.parametrize("layout", ["trailing", "interleaved"])
+def test_segment_combine_dead_blocks_cover_every_tile(op, layout):
+    """Edge blocks with no live row (trailing padding, or a masked middle
+    of the frontier) must neither crowd tiles out of the kernel's work
+    list nor leave a tile unwritten: 8 blocks against 32 output tiles."""
+
+    rng = np.random.default_rng(11)
+    E, N = 8 * 1024, 4096
+    vals = rng.normal(size=(E, 3)).astype(np.float32)
+    if layout == "trailing":
+        ids = np.full(E, -1, np.int32)
+        ids[:900] = np.sort(rng.integers(0, 100, 900))
+        act = None
+    else:
+        ids = np.sort(rng.integers(0, N, E)).astype(np.int32)
+        act = np.zeros(E, bool)
+        act[:500] = act[-500:] = True
+        act = jnp.asarray(act)
+    out = segment_combine(jnp.asarray(vals), jnp.asarray(ids), N, op,
+                          edge_active=act, interpret=True)
+    ref = segment_combine_reference(jnp.asarray(vals), jnp.asarray(ids), N,
+                                    op, edge_active=act)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)

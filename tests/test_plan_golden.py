@@ -523,21 +523,23 @@ def test_exchange_and_chunking_plan_notes_golden():
         (("rank", "tc"),), (), 1 << 20, MeshSpec((("data", 8),)),
         hbm_budget=1 << 22, **_X_KW,
     )
+    # The 3M-row EDB slab holds every row (4M-row cap), so the budget sees
+    # its true 36 MiB and streams it in 9 chunks of under 4 MiB each.
     assert plan.notes == (
-        "storage-selection(n=1048576, edge=row-table[cap=1048576], "
+        "storage-selection(n=1048576, edge=row-table[cap=4194304], "
         "rank=row-table[cap=131072], tc=row-table[cap=1048576])",
         "loop-invariant-caching(edb-grids)",
         "spmd(gspmd data-parallel x8)",
-        "exchange(edge: bucket-a2a[cap=1048576])",
+        "exchange(edge: bucket-a2a[cap=4194304])",
         "exchange(rank: psum-scatter)",
         "exchange(tc: bucket-a2a[cap=1048576])",
-        "chunking(edge: 3 chunks, budget=4194304B)",
+        "chunking(edge: 9 chunks, budget=4194304B)",
     ), plan.notes
     assert plan.exchanges == {
         "edge": "bucket-a2a", "rank": "psum-scatter", "tc": "bucket-a2a"}
     assert plan.exchange_caps == {
-        "edge": 1048576, "rank": 16384, "tc": 1048576}
-    assert plan.chunks == {"edge": 3}
+        "edge": 4194304, "rank": 16384, "tc": 1048576}
+    assert plan.chunks == {"edge": 9}
 
 
 def test_single_shard_plan_has_no_exchange_notes():
