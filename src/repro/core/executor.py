@@ -66,6 +66,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import algebra, stratify
@@ -1021,14 +1022,33 @@ def _groupby_rows(op: algebra.GroupBy, child: _Rows, ctx: _Ctx) -> _Rows:
     )
 
 
+# The device scope each logical operator's work runs under (storage
+# independent).  Scope names are part of the trace's contract: the
+# benchmark's per-operator device times read them.
+_OP_SCOPES = {
+    algebra.ScanEDB: "scan", algebra.ScanState: "scan",
+    algebra.ScanView: "scan", algebra.Frontier: "scan",
+    algebra.Delta: "scan", algebra.Join: "join", algebra.Cross: "cross",
+    algebra.AntiJoin: "antijoin", algebra.Select: "select",
+    algebra.Project: "project", algebra.Extend: "extend",
+    algebra.Apply: "apply", algebra.GroupBy: "groupby",
+    algebra.Unnest: "unnest",
+}
+
+
 def _eval(op: algebra.LogicalOp, ctx: _Ctx) -> _Inter:
     if ctx.shared and id(op) in ctx.shared:
         hit = ctx.memo.get(id(op))
         if hit is None:
-            hit = _eval_inner(op, ctx)
+            hit = _eval_scoped(op, ctx)
             ctx.memo[id(op)] = hit
         return hit
-    return _eval_inner(op, ctx)
+    return _eval_scoped(op, ctx)
+
+
+def _eval_scoped(op: algebra.LogicalOp, ctx: _Ctx):
+    with jax.named_scope(_OP_SCOPES.get(type(op), type(op).__name__)):
+        return _eval_inner(op, ctx)
 
 
 def _eval_inner(op: algebra.LogicalOp, ctx: _Ctx):
@@ -1577,6 +1597,17 @@ class GenericExecutable:
             chunked=frozenset(self.chunked_edb),
         )
 
+    def _fire(self, df, ctx: _Ctx) -> Dict[str, Any]:
+        """Evaluate one rule's body and materialize it into its head, under
+        the rule's device scope."""
+
+        ctx.label = df.label
+        ctx.exchange_target = df.target
+        with jax.named_scope(f"rule.{df.label}"):
+            inter = _eval(df.op, ctx)
+            with jax.named_scope("materialize"):
+                return self._materialize(df, inter, ctx)
+
     def _materialize(self, df, inter, ctx: _Ctx) -> Dict[str, Any]:
         """Lower a rule-body intermediate into the head predicate's storage
         (dense grid or row table), inserting the boundary converter when the
@@ -1672,6 +1703,7 @@ class GenericExecutable:
             "values": {p: v[take] for p, v in out["values"].items()},
         }
 
+    @jax.named_scope("merge")
     def _merge(self, pred: str, outs, ctx: _Ctx) -> Dict[str, Any]:
         if not outs:
             return self._empty_out(pred)
@@ -1812,9 +1844,7 @@ class GenericExecutable:
 
         views = ctx.views
         for df in dataflows:
-            ctx.label = df.label
-            ctx.exchange_target = df.target
-            out = self._materialize(df, _eval(df.op, ctx), ctx)
+            out = self._fire(df, ctx)
             if df.next_state:
                 acc.setdefault(df.target, []).append(out)
             else:
@@ -1827,24 +1857,27 @@ class GenericExecutable:
         new_state = dict(state)
         for pred in phase.carried:
             out = self._merge(pred, acc.get(pred, []), ctx)
-            if self._is_row(pred):
-                delta, _ = self._diff_rows(state[pred], out)
-            else:
-                delta = jnp.logical_and(
-                    out["present"],
-                    self._diff(state[pred], out["present"], out["values"]),
-                )
+            with jax.named_scope("diff"):
+                if self._is_row(pred):
+                    delta, _ = self._diff_rows(state[pred], out)
+                else:
+                    delta = jnp.logical_and(
+                        out["present"],
+                        self._diff(state[pred], out["present"],
+                                   out["values"]),
+                    )
             entry = dict(out)
             entry["delta"] = delta
             if self._any_row:
                 # Fold every capacity flag this step raised (including
                 # the merges above) into the carried overflow leaf.
-                step_of = functools.reduce(
-                    jnp.logical_or, ctx.overflow, of_extra
-                )
-                entry["overflow"] = jnp.logical_or(
-                    state[pred].get("overflow", False), step_of
-                )
+                with jax.named_scope("overflow"):
+                    step_of = functools.reduce(
+                        jnp.logical_or, ctx.overflow, of_extra
+                    )
+                    entry["overflow"] = jnp.logical_or(
+                        state[pred].get("overflow", False), step_of
+                    )
             new_state[pred] = entry
         return new_state
 
@@ -1861,6 +1894,7 @@ class GenericExecutable:
         return step
 
     def _phase_converged(self, phase: _Phase) -> Callable:
+        @jax.named_scope("diff")
         def conv(prev, new):
             same = jnp.asarray(True)
             for pred in phase.carried:
@@ -1896,8 +1930,6 @@ class GenericExecutable:
         ctx = self._ctx(state, views, materialized, j, relations=relations)
         base_edb = ctx.row_edb
         for df in dataflows:
-            ctx.label = df.label
-            ctx.exchange_target = df.target
             refs = self._chunk_refs(df)
             if refs:
                 # Out-of-core scan in a once-fired rule group: stream the
@@ -1908,14 +1940,12 @@ class GenericExecutable:
                 for chunk in self.chunked_edb[pred]:
                     ctx.row_edb = dict(base_edb)
                     ctx.row_edb[pred] = self._put_chunk(chunk)
-                    outs.append(
-                        self._materialize(df, _eval(df.op, ctx), ctx)
-                    )
+                    outs.append(self._fire(df, ctx))
                 ctx.row_edb = base_edb
                 out = self._merge(df.target, outs, ctx) \
                     if len(outs) > 1 else outs[0]
             else:
-                out = self._materialize(df, _eval(df.op, ctx), ctx)
+                out = self._fire(df, ctx)
             if df.target not in acc:
                 order.append(df.target)
             acc.setdefault(df.target, []).append(out)
@@ -1978,9 +2008,7 @@ class GenericExecutable:
                     )
                 out_acc = dict(acc)
                 for df in _dfs:
-                    ctx.label = df.label
-                    ctx.exchange_target = df.target
-                    out = self._materialize(df, _eval(df.op, ctx), ctx)
+                    out = self._fire(df, ctx)
                     out_acc[df.target] = self._merge(
                         df.target, [out_acc[df.target], out], ctx
                     )
@@ -2569,16 +2597,18 @@ class GenericExecutable:
         t0 = time.perf_counter()
         place = self._placer()
         state: Dict[str, Dict[str, Any]] = {}
-        for phase in self.phases:
-            for pred in phase.carried:
-                state[pred] = jax.tree_util.tree_map(
-                    place, self._empty_entry(pred)
-                )
-        materialized: Dict[str, Dict[str, Any]] = {}
-        for out, entry in self._run_rules_once(
-            self.prelude, state, materialized, jnp.int32(0), relations=prels
-        ).items():
-            materialized[out] = entry
+        with TraceAnnotation("executor.prelude"):
+            for phase in self.phases:
+                for pred in phase.carried:
+                    state[pred] = jax.tree_util.tree_map(
+                        place, self._empty_entry(pred)
+                    )
+            materialized: Dict[str, Dict[str, Any]] = {}
+            for out, entry in self._run_rules_once(
+                self.prelude, state, materialized, jnp.int32(0),
+                relations=prels,
+            ).items():
+                materialized[out] = entry
 
         # Resume cursor: phase to continue in (1-based), iteration within it
         # (checkpoints are written post-init, so a restored state never needs
@@ -2614,17 +2644,18 @@ class GenericExecutable:
                 continue
             resumed = restored_from_disk and k == start_phase
             if not resumed:
-                inits = self._run_rules_once(
-                    phase.init, state, materialized, jnp.int32(0),
-                    relations=prels,
-                )
-                for pred in phase.carried:
-                    entry = inits.get(pred)
-                    if entry is None:
-                        continue
-                    state[pred] = jax.tree_util.tree_map(
-                        place, self._init_entry(entry)
+                with TraceAnnotation("executor.phase_init", phase=k):
+                    inits = self._run_rules_once(
+                        phase.init, state, materialized, jnp.int32(0),
+                        relations=prels,
                     )
+                    for pred in phase.carried:
+                        entry = inits.get(pred)
+                        if entry is None:
+                            continue
+                        state[pred] = jax.tree_util.tree_map(
+                            place, self._init_entry(entry)
+                        )
             chunked_phase = any(self._chunk_refs(df) for df in phase.body)
             step = self._phase_step(phase, materialized, relations=prels)
             conv = self._phase_converged(phase)
@@ -2713,44 +2744,47 @@ class GenericExecutable:
             # Lossless overflow policy: any capacity flag raised inside the
             # (jitted) fixpoint surfaces here, before the phase's results
             # are consumed.
-            for pred in phase.carried:
-                of = state[pred].get("overflow")
-                if of is not None and bool(of):
-                    raise _RowCapacityOverflow()
+            with TraceAnnotation("executor.overflow_check"):
+                for pred in phase.carried:
+                    of = state[pred].get("overflow")
+                    if of is not None and bool(of):
+                        raise _RowCapacityOverflow()
             it = (start_iter if resumed else 0) + res.iterations
             total += res.iterations
             phase_iters.append(it)
             all_conv = all_conv and res.converged
             # Final views of this phase (frontier reads at the fixpoint),
             # then the post-stratum rules gated on its convergence.
-            finals = self._run_rules_once(
-                tuple(df for df in phase.body if not df.next_state)
-                + phase.finals,
-                state, materialized, jnp.int32(it), relations=prels,
-            )
-            materialized.update(finals)
-            posts = self._run_rules_once(
-                phase.post, state, materialized, jnp.int32(it),
-                relations=prels,
-            )
-            materialized.update(posts)
+            with TraceAnnotation("executor.finals", phase=k):
+                finals = self._run_rules_once(
+                    tuple(df for df in phase.body if not df.next_state)
+                    + phase.finals,
+                    state, materialized, jnp.int32(it), relations=prels,
+                )
+                materialized.update(finals)
+                posts = self._run_rules_once(
+                    phase.post, state, materialized, jnp.int32(it),
+                    relations=prels,
+                )
+                materialized.update(posts)
         if store is not None:
             store.wait()  # surface any pending async-save failure
 
         out: Dict[str, Any] = {}
-        for pred, entry in list(materialized.items()) + [
-            (p, state[p]) for ph in self.phases for p in ph.carried
-        ]:
-            keys, _ = self.sigs[pred]
-            if self._is_row(pred):
-                out[pred] = self._rows_to_relation(pred, entry)
-            else:
-                out[pred] = Relation(
-                    n=self.domain,
-                    key_positions=keys,
-                    present=entry["present"],
-                    values=dict(entry["values"]),
-                )
+        with TraceAnnotation("executor.result"):
+            for pred, entry in list(materialized.items()) + [
+                (p, state[p]) for ph in self.phases for p in ph.carried
+            ]:
+                keys, _ = self.sigs[pred]
+                if self._is_row(pred):
+                    out[pred] = self._rows_to_relation(pred, entry)
+                else:
+                    out[pred] = Relation(
+                        n=self.domain,
+                        key_positions=keys,
+                        present=entry["present"],
+                        values=dict(entry["values"]),
+                    )
         return FixpointResult(
             state=out,
             iterations=total,
@@ -3333,25 +3367,28 @@ def _compact_and_gather(prog, j, state, active, src, dst,
         edge_data = jax.tree_util.tree_map(
             lambda e: jnp.zeros((1,) + e.shape[1:], e.dtype), edge_data
         )
-    mask = jnp.take(active, src, axis=0)
-    if pad is not None:
-        mask = jnp.logical_and(mask, jnp.logical_not(pad))
-    idx, valid = compact_active_edges(mask, cap)
-    idx_c = jnp.minimum(idx, src.shape[0] - 1)
-    src_c = jnp.take(src, idx_c)
-    dst_c = jnp.take(dst, idx_c)
-    edata_c = (
-        None if edge_data is None else jax.tree_util.tree_map(
-            lambda e: jnp.take(e, idx_c, axis=0), edge_data
+    with jax.named_scope("compact"):
+        mask = jnp.take(active, src, axis=0)
+        if pad is not None:
+            mask = jnp.logical_and(mask, jnp.logical_not(pad))
+        idx, valid = compact_active_edges(mask, cap)
+        idx_c = jnp.minimum(idx, src.shape[0] - 1)
+        src_c = jnp.take(src, idx_c)
+        dst_c = jnp.take(dst, idx_c)
+        edata_c = (
+            None if edge_data is None else jax.tree_util.tree_map(
+                lambda e: jnp.take(e, idx_c, axis=0), edge_data
+            )
         )
-    )
-    src_state = jax.tree_util.tree_map(
-        lambda s: jnp.take(s, src_c, axis=0), state
-    )
-    payload = prog.message(j, src_state, edata_c)
+    with jax.named_scope("gather"):
+        src_state = jax.tree_util.tree_map(
+            lambda s: jnp.take(s, src_c, axis=0), state
+        )
+        payload = prog.message(j, src_state, edata_c)
     return dst_c, payload, valid
 
 
+@jax.named_scope("apply")
 def _apply_and_merge(prog, j, state, inbox, got):
     """Shared superstep epilogue (O8..O10 + L7): run the apply UDF, keep the
     old state wherever no message arrived, and halt those vertices.  Every
@@ -3423,26 +3460,29 @@ def build_pregel_steps(prog, graph, plan, mesh,
         of the source vertex); ``dst_l`` holds global destination ids.
         """
 
-        # O7 index join: probe source state by gather (B-tree probe).
-        src_state = jax.tree_util.tree_map(
-            lambda s: jnp.take(s, src_l, axis=0), state_shard
-        )
-        src_active = jnp.take(active_shard, src_l, axis=0)
-        payload = prog.message(j, src_state, edata_l)
-        # Vote-to-halt: inactive sources contribute the combine identity
-        # (a per-column identity row for structured monoids like argmin).
-        payload = jnp.where(
-            src_active.reshape((-1,) + (1,) * (payload.ndim - 1)),
-            payload,
-            get_monoid(op).identity_like(payload),
-        )
-        # O15 sender combine + connector + O14 receiver combine.
-        inbox = connector(dst_l, payload, graph.n_vertices, batch_axes, op)
-        got_msg = connector(
-            dst_l,
-            jnp.where(src_active, 1.0, 0.0),
-            graph.n_vertices, batch_axes, "sum",
-        ) > 0
+        with jax.named_scope("gather"):
+            # O7 index join: probe source state by gather (B-tree probe).
+            src_state = jax.tree_util.tree_map(
+                lambda s: jnp.take(s, src_l, axis=0), state_shard
+            )
+            src_active = jnp.take(active_shard, src_l, axis=0)
+            payload = prog.message(j, src_state, edata_l)
+            # Vote-to-halt: inactive sources contribute the combine
+            # identity (a per-column identity row for structured monoids
+            # like argmin).
+            payload = jnp.where(
+                src_active.reshape((-1,) + (1,) * (payload.ndim - 1)),
+                payload,
+                get_monoid(op).identity_like(payload),
+            )
+            sent = jnp.where(src_active, 1.0, 0.0)
+        with jax.named_scope("exchange"):
+            # O15 sender combine + connector + O14 receiver combine.
+            inbox = connector(dst_l, payload, graph.n_vertices, batch_axes,
+                              op)
+            got_msg = connector(
+                dst_l, sent, graph.n_vertices, batch_axes, "sum",
+            ) > 0
         # O8 apply + O9/O10 masked in-place state update (non-null check L7):
         # vertices with no inbound messages keep their state and stay halted.
         return _apply_and_merge(prog, j, state_shard, inbox, got_msg)
@@ -3512,29 +3552,31 @@ def build_pregel_steps(prog, graph, plan, mesh,
         espec = jax.tree_util.tree_map(lambda _: spec1, edata)
 
         def sharded(state, active, src_l, dst_l, pad_l, edata_l, vdata_l, j):
-            # Mask padded edges: treat their source as inactive.
-            act = jnp.logical_and(
-                jnp.take(active, src_l, axis=0), jnp.logical_not(pad_l)
-            )
-            src_state = jax.tree_util.tree_map(
-                lambda s: jnp.take(s, src_l, axis=0), state
-            )
-            payload = prog.message(j, src_state, edata_l)
-            payload = jnp.where(
-                act.reshape((-1,) + (1,) * (payload.ndim - 1)),
-                payload,
-                get_monoid(op).identity_like(payload),
-            )
-            dst_eff = jnp.where(pad_l, -1, dst_l)
-            inbox = connector(
-                jnp.where(dst_eff < 0, 0, dst_eff),
-                payload, graph.n_vertices, batch_axes, op,
-            )
-            got = connector(
-                jnp.where(dst_eff < 0, 0, dst_eff),
-                jnp.where(act, 1.0, 0.0),
-                graph.n_vertices, batch_axes, "sum",
-            ) > 0
+            with jax.named_scope("gather"):
+                # Mask padded edges: treat their source as inactive.
+                act = jnp.logical_and(
+                    jnp.take(active, src_l, axis=0), jnp.logical_not(pad_l)
+                )
+                src_state = jax.tree_util.tree_map(
+                    lambda s: jnp.take(s, src_l, axis=0), state
+                )
+                payload = prog.message(j, src_state, edata_l)
+                payload = jnp.where(
+                    act.reshape((-1,) + (1,) * (payload.ndim - 1)),
+                    payload,
+                    get_monoid(op).identity_like(payload),
+                )
+                sent = jnp.where(act, 1.0, 0.0)
+            with jax.named_scope("exchange"):
+                dst_eff = jnp.where(pad_l, -1, dst_l)
+                inbox = connector(
+                    jnp.where(dst_eff < 0, 0, dst_eff),
+                    payload, graph.n_vertices, batch_axes, op,
+                )
+                got = connector(
+                    jnp.where(dst_eff < 0, 0, dst_eff),
+                    sent, graph.n_vertices, batch_axes, "sum",
+                ) > 0
             return _apply_and_merge(prog, j, state, inbox, got)
 
         state_specs = P(batch_axes)
@@ -3553,6 +3595,7 @@ def build_pregel_steps(prog, graph, plan, mesh,
 
         # -- sharded semi-naive (delta-frontier) machinery ------------------
 
+        @jax.named_scope("compact")
         def _local_count(active, src_l, pad_l):
             mask = jnp.logical_and(
                 jnp.take(active, src_l, axis=0), jnp.logical_not(pad_l)
@@ -3598,7 +3641,8 @@ def build_pregel_steps(prog, graph, plan, mesh,
                         dst_c, fused, valid, graph.n_vertices, batch_axes,
                         op, flag_cols=1,
                     )
-                inbox, got = fused_got_exchange(ex, payload, valid, op)
+                with jax.named_scope("exchange"):
+                    inbox, got = fused_got_exchange(ex, payload, valid, op)
                 return _apply_and_merge(prog, j, state, inbox, got)
 
             wrapped = shard_map(
@@ -3647,7 +3691,8 @@ def build_pregel_steps(prog, graph, plan, mesh,
                         dst_c, fused, valid, graph.n_vertices, (), op,
                         flag_cols=1,
                     )
-                inbox, got = fused_got_exchange(ex, payload, valid, op)
+                with jax.named_scope("exchange"):
+                    inbox, got = fused_got_exchange(ex, payload, valid, op)
                 return _apply_and_merge(prog, j, state, inbox, got)
 
             return jit_hoisted(step)
@@ -3678,6 +3723,7 @@ def build_imru_step(task, records, plan, mesh, mesh_spec):
     ) or ("data",)
     n_mb = plan.microbatches
 
+    @jax.named_scope("map")
     def local_partial(records_shard: Any, model: Any) -> Any:
         """map + sender-side early aggregation over the local shard, with
         optional microbatching (Fig. 5 O5+O6)."""
@@ -3729,12 +3775,14 @@ def build_imru_step(task, records, plan, mesh, mesh_spec):
 
         def sharded_step(records_shard, model, j):
             partial = local_partial(records_shard, model)
-            total = reduce_tree(
-                partial, reduce_sched,
-                data_axes=tuple(a for a in ("data",) if a in batch_axes),
-                pod_axis="pod",
-            )
-            return task.update(j, model, total)
+            with jax.named_scope("reduce"):
+                total = reduce_tree(
+                    partial, reduce_sched,
+                    data_axes=tuple(a for a in ("data",) if a in batch_axes),
+                    pod_axis="pod",
+                )
+            with jax.named_scope("update"):
+                return task.update(j, model, total)
 
         step_inner = shard_map(
             sharded_step, mesh=mesh,
@@ -3745,7 +3793,8 @@ def build_imru_step(task, records, plan, mesh, mesh_spec):
     else:
         def step_fn(model, j):
             partial = local_partial(records, model)
-            return task.update(j, model, partial)
+            with jax.named_scope("update"):
+                return task.update(j, model, partial)
 
         step = jit_hoisted(step_fn)
 

@@ -22,10 +22,20 @@ Termination mirrors Appendix B.2: either the temporal argument hits its
 finite bound (``max_iters``) or the update UDF derives no new facts
 (``converged(state)`` — e.g. G3's ``M != NewM`` is empty, L8's send set is
 empty).
+
+Both drivers name their host phases in the profiler's trace
+(``jax.profiler.TraceAnnotation``, free while no profiler runs):
+``fixpoint.trace`` around each new step signature :class:`jit_hoisted`
+traces and first dispatches, ``fixpoint.device_loop`` around
+:func:`device_fixpoint`, and per host-driver iteration
+``fixpoint.iteration`` (its index, and the adaptive mode where one was
+chosen) holding ``fixpoint.dispatch``, ``fixpoint.wait`` and
+``fixpoint.converged``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import time
@@ -36,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "FixpointResult",
@@ -66,11 +77,13 @@ class jit_hoisted:
         self.fn = fn
         self._traced: dict = {}
 
-    def _entry(self, args):
+    def _entry(self, args, span=None):
         flat, tree = jax.tree_util.tree_flatten(args)
         key = (tree, tuple(jax.typeof(a) for a in flat))
         entry = self._traced.get(key)
         if entry is None:
+            if span is not None:
+                span.enter_context(TraceAnnotation("fixpoint.trace"))
             closed, out_shape = jax.make_jaxpr(
                 self.fn, return_shape=True
             )(*args)
@@ -92,8 +105,12 @@ class jit_hoisted:
         return entry, flat
 
     def __call__(self, *args):
-        (run, consts, out_tree, _), flat = self._entry(args)
-        return jax.tree_util.tree_unflatten(out_tree, run(consts, *flat))
+        # A new signature opens a span that also covers the lowering and
+        # compile (or cache load) of its first dispatch: a trace holds one
+        # per program obtained.
+        with contextlib.ExitStack() as span:
+            (run, consts, out_tree, _), flat = self._entry(args, span)
+            return jax.tree_util.tree_unflatten(out_tree, run(consts, *flat))
 
     def lower(self, *args):
         (run, consts, _, _), flat = self._entry(args)
@@ -152,7 +169,8 @@ def device_fixpoint(
     def step(carry):
         state, j, _ = carry
         new_state = body(state, j)
-        done = converged(state, new_state)
+        with jax.named_scope("converged"):
+            done = converged(state, new_state)
         return new_state, j + 1, done
 
     t0 = time.perf_counter()
@@ -160,8 +178,9 @@ def device_fixpoint(
         lambda s: lax.while_loop(
             cond, step, (s, jnp.int32(0), jnp.bool_(False)))
     )
-    state, iters, done = fn(init_state)
-    state = jax.block_until_ready(state)
+    with TraceAnnotation("fixpoint.device_loop"):
+        state, iters, done = fn(init_state)
+        state = jax.block_until_ready(state)
     return FixpointResult(
         state=state,
         iterations=int(iters),
@@ -209,7 +228,6 @@ class DriverConfig:
     # trailing-mean iteration time, log + count it (on real pods: re-issue the
     # slow shard's collective participant / drop to backup reducer).
     straggler_factor: float = 3.0
-    log_every: int = 10
 
 
 class HostFixpointDriver:
@@ -287,65 +305,73 @@ class HostFixpointDriver:
         t_start = time.perf_counter()
         done = False
         while j < cfg.max_iters and not done:
-            t0 = time.perf_counter()
-            try:
-                if self.fail_at is not None and j == self.fail_at \
-                        and not self._failed_once:
-                    self._failed_once = True
-                    raise RuntimeError(f"injected failure at iteration {j}")
-                if self.injector is not None:
-                    self.injector.maybe_fail(j)
-                step_fn = self.step
-                if self.select_step is not None:
-                    step_fn, mode = self.select_step(state, j)
-                    self.mode_history.append(mode)
-                new_state = step_fn(state, j)
-                new_state = jax.block_until_ready(new_state)
-            except Exception as exc:  # noqa: BLE001 — FT boundary
-                if not _replayable(exc):
-                    raise
-                self.restarts += 1
-                if self.restarts > cfg.max_restarts or self.restore is None:
-                    raise
-                logger.warning(
-                    "iteration %d failed (%s); restoring from checkpoint "
-                    "(restart %d/%d)", j, exc, self.restarts, cfg.max_restarts
-                )
-                state, j = self.restore()
-                # Iteration times recorded before the failure belong to the
-                # aborted attempt; restart the straggler window so the
-                # trailing mean reflects only post-restore iterations.
-                self._window_start = len(self.iter_times)
-                # Drop mode labels recorded for the failed attempt and for
-                # iterations about to be replayed, keeping mode_history[i]
-                # aligned with iteration start_iter + i.
-                del self.mode_history[max(j - start_iter, 0):]
-                continue
-
-            dt = time.perf_counter() - t0
-            self.iter_times.append(dt)
-            window = self.iter_times[self._window_start:]
-            if len(window) > 3:
-                trailing = sum(window[-11:-1]) / len(window[-11:-1])
-                if dt > cfg.straggler_factor * trailing:
-                    self.straggler_events += 1
+            with TraceAnnotation("fixpoint.iteration", iteration=j) as span:
+                t0 = time.perf_counter()
+                try:
+                    if self.fail_at is not None and j == self.fail_at \
+                            and not self._failed_once:
+                        self._failed_once = True
+                        raise RuntimeError(
+                            f"injected failure at iteration {j}")
+                    if self.injector is not None:
+                        self.injector.maybe_fail(j)
+                    step_fn = self.step
+                    if self.select_step is not None:
+                        step_fn, mode = self.select_step(state, j)
+                        self.mode_history.append(mode)
+                        span.set_metadata(mode=mode)
+                    with TraceAnnotation("fixpoint.dispatch"):
+                        new_state = step_fn(state, j)
+                    with TraceAnnotation("fixpoint.wait"):
+                        new_state = jax.block_until_ready(new_state)
+                except Exception as exc:  # noqa: BLE001 — FT boundary
+                    if not _replayable(exc):
+                        raise
+                    self.restarts += 1
+                    if self.restarts > cfg.max_restarts \
+                            or self.restore is None:
+                        raise
                     logger.warning(
-                        "straggler: iteration %d took %.3fs (%.1fx trailing "
-                        "mean %.3fs)", j, dt, dt / trailing, trailing,
+                        "iteration %d failed (%s); restoring from checkpoint "
+                        "(restart %d/%d)", j, exc, self.restarts,
+                        cfg.max_restarts
                     )
-                    if self.on_straggler is not None:
-                        self.on_straggler(j, dt)
+                    state, j = self.restore()
+                    # Iteration times recorded before the failure belong to
+                    # the aborted attempt; restart the straggler window so
+                    # the trailing mean reflects only post-restore
+                    # iterations.
+                    self._window_start = len(self.iter_times)
+                    # Drop mode labels recorded for the failed attempt and
+                    # for iterations about to be replayed, keeping
+                    # mode_history[i] aligned with iteration start_iter + i.
+                    del self.mode_history[max(j - start_iter, 0):]
+                    continue
 
-            done = bool(self.converged(state, new_state))
-            state = new_state
-            j += 1
-            if self.on_iteration is not None:
-                self.on_iteration(j, dt)
-            if cfg.checkpoint_every and self.save is not None \
-                    and j % cfg.checkpoint_every == 0:
-                self.save(state, j)
-            if cfg.log_every and j % cfg.log_every == 0:
-                logger.info("iteration %d done in %.3fs", j, dt)
+                dt = time.perf_counter() - t0
+                self.iter_times.append(dt)
+                window = self.iter_times[self._window_start:]
+                if len(window) > 3:
+                    trailing = sum(window[-11:-1]) / len(window[-11:-1])
+                    if dt > cfg.straggler_factor * trailing:
+                        self.straggler_events += 1
+                        logger.warning(
+                            "straggler: iteration %d took %.3fs (%.1fx "
+                            "trailing mean %.3fs)", j, dt, dt / trailing,
+                            trailing,
+                        )
+                        if self.on_straggler is not None:
+                            self.on_straggler(j, dt)
+
+                with TraceAnnotation("fixpoint.converged"):
+                    done = bool(self.converged(state, new_state))
+                state = new_state
+                j += 1
+                if self.on_iteration is not None:
+                    self.on_iteration(j, dt)
+                if cfg.checkpoint_every and self.save is not None \
+                        and j % cfg.checkpoint_every == 0:
+                    self.save(state, j)
 
         if self.save is not None and cfg.checkpoint_every:
             self.save(state, j)

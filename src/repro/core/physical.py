@@ -314,6 +314,7 @@ def decompress_int8(q: jax.Array, scale: jax.Array, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("combine")
 def segment_combine_sorted(
     values: jax.Array,
     segment_ids: jax.Array,
@@ -400,6 +401,7 @@ def segment_combine_sorted(
     raise ValueError(f"unsupported combine op {op!r}")
 
 
+@jax.named_scope("combine")
 def scatter_combine(
     values: jax.Array,
     segment_ids: jax.Array,
@@ -837,6 +839,7 @@ def row_codes(ids: jax.Array, n: int) -> jax.Array:
     return code
 
 
+@jax.named_scope("sort")
 def sort_row_codes(
     codes: jax.Array, valid: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -858,6 +861,7 @@ def sort_row_codes(
     return perm, sorted_key, jnp.sum(valid.astype(jnp.int32))
 
 
+@jax.named_scope("runs")
 def unique_row_runs(
     sorted_key: jax.Array, n_valid: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
@@ -875,6 +879,7 @@ def unique_row_runs(
     return is_new, seg
 
 
+@jax.named_scope("expand")
 def join_row_codes(
     l_codes: jax.Array,
     l_valid: jax.Array,

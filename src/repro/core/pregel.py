@@ -191,9 +191,9 @@ class PregelExecutable:
 
         if self._edge_count_fn is None:
             src = self.graph.src
-            self._edge_count_fn = jit_hoisted(
+            self._edge_count_fn = jit_hoisted(jax.named_scope("compact")(
                 lambda a: jnp.sum(jnp.take(a, src).astype(jnp.int32))
-            )
+            ))
         return int(self._edge_count_fn(active))
 
     def shard_edge_counts(self, active: jax.Array) -> np.ndarray:
