@@ -892,6 +892,11 @@ def join_row_codes(
     The right table is sorted once; each left row finds its matching run by
     binary search, and a prefix sum over per-row match counts lays the pairs
     out densely into ``out_cap`` slots (the static-shape pair expansion).
+    Slot ``t`` belongs to left row ``#{i : offs[i] <= t}``: the prefix sum
+    of a histogram of the run ends ``offs``, so the slot-to-row map costs a
+    scatter of ``cap_l`` sorted indices and a cumsum over ``out_cap`` slots,
+    not a binary search per slot.  One gather of a per-left-row offset then
+    gives each slot its right row.
     Returns ``(li, ri, valid, overflow)``: left/right row indices per output
     slot, the slot validity mask, and a traced flag set when the true pair
     count exceeds ``out_cap`` (pairs beyond the cap are dropped — the caller
@@ -910,10 +915,16 @@ def join_row_codes(
     total = offs[-1]
     overflow = jnp.logical_or(total > out_cap, total < 0)
     t = jnp.arange(out_cap, dtype=jnp.int32)
-    li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
-    li = jnp.minimum(li, cap_l - 1)
-    before = offs[li] - cnt[li]
-    rpos = start[li] + (t - before)
+    # Run ends past the cap, or wrapped negative (both flagged above), drop.
+    pos = jnp.where((offs >= 0) & (offs < out_cap), offs, out_cap)
+    hist = jnp.zeros((out_cap,), jnp.int32).at[pos].add(
+        1, mode="drop", indices_are_sorted=True
+    )
+    li = jnp.minimum(jnp.cumsum(hist), cap_l - 1)
+    # Slot t of left row i reads sorted right row start[i] + t - (offs[i] -
+    # cnt[i]); fold the per-row terms into one offset.
+    delta = start - (offs - cnt)
+    rpos = t + delta[li]
     ri = perm_r[jnp.clip(rpos, 0, cap_r - 1)]
     valid = t < total
     return li, ri, valid, overflow

@@ -11,7 +11,9 @@ deterministic ``tests/_hypothesis_compat`` replay shim.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.extend import core as jcore
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -27,6 +29,7 @@ from repro.core.executor import (
     _project_rows,
     _Rows,
 )
+from repro.core.physical import join_row_codes, sort_row_codes
 
 CAP = 64
 
@@ -185,3 +188,129 @@ def test_join_rows_residual_value_equality():
     valid = np.asarray(out.valid)
     ids = np.asarray(out.ids)[valid]
     assert set(map(tuple, ids.tolist())) == {(1, 5)}
+
+
+def _join_row_codes_by_search(l_codes, l_valid, r_codes, r_valid, out_cap):
+    """The pair expansion as a binary search per slot: each of the
+    ``out_cap`` slots searches the run ends ``offs`` for its left row and
+    gathers that row's offset, start and count."""
+
+    cap_l, cap_r = l_codes.shape[0], r_codes.shape[0]
+    perm_r, r_skey, r_nv = sort_row_codes(r_codes, r_valid)
+    start = jnp.searchsorted(r_skey, l_codes, side="left").astype(jnp.int32)
+    end = jnp.searchsorted(r_skey, l_codes, side="right").astype(jnp.int32)
+    end = jnp.minimum(end, r_nv)
+    cnt = jnp.where(l_valid, jnp.maximum(end - start, 0), 0)
+    offs = jnp.cumsum(cnt)
+    total = offs[-1]
+    overflow = jnp.logical_or(total > out_cap, total < 0)
+    t = jnp.arange(out_cap, dtype=jnp.int32)
+    li = jnp.searchsorted(offs, t, side="right").astype(jnp.int32)
+    li = jnp.minimum(li, cap_l - 1)
+    before = offs[li] - cnt[li]
+    rpos = start[li] + (t - before)
+    ri = perm_r[jnp.clip(rpos, 0, cap_r - 1)]
+    valid = t < total
+    return li, ri, valid, overflow
+
+
+_SENTINEL = 0xFFFFFFFF
+
+
+def _pair_case(name):
+    """``(l_codes, l_valid, r_codes, r_valid, out_cap, expect)``; ``expect``
+    holds what the case is built to show: the overflow flag and the number
+    of valid slots."""
+
+    rng = np.random.default_rng(sum(map(ord, name)))
+    u32 = lambda a: np.asarray(a, np.uint32)  # noqa: E731
+    if name == "no_valid_left":
+        return (u32(rng.integers(0, 4, 8)), np.zeros(8, bool),
+                u32(rng.integers(0, 4, 12)), np.ones(12, bool), 16,
+                dict(overflow=False, pairs=0))
+    if name == "no_valid_right":
+        return (u32(rng.integers(0, 4, 8)), np.ones(8, bool),
+                u32(rng.integers(0, 4, 12)), np.zeros(12, bool), 16,
+                dict(overflow=False, pairs=0))
+    if name == "no_key_matches":
+        return (u32(np.arange(8)), np.ones(8, bool),
+                u32(np.arange(100, 112)), np.ones(12, bool), 16,
+                dict(overflow=False, pairs=0))
+    if name == "one_left_row_overflows":
+        lc = u32([5, 3, 7, 1])
+        return (lc, np.ones(4, bool), u32(np.full(20, 3)), np.ones(20, bool),
+                8, dict(overflow=True, pairs=8))
+    if name == "total_equals_cap":
+        lc = u32([0, 1, 2, 3, 0])
+        lv = np.array([True, True, True, True, False])
+        rc = u32([3] * 8 + [1] * 5 + [0] * 3 + [9] * 4)
+        rv = np.ones(20, bool)
+        return lc, lv, rc, rv, 16, dict(overflow=False, pairs=16)
+    if name == "duplicate_right_codes":
+        lc = u32(rng.integers(0, 5, 24))
+        lv = rng.random(24) > 0.2
+        rc = u32(rng.integers(0, 3, 40))
+        rv = rng.random(40) > 0.2
+        return lc, lv, rc, rv, 256, dict(overflow=False, pairs=None)
+    if name == "one_left_row":
+        return (u32([2]), np.ones(1, bool), u32([2, 0, 2, 2, 1, 2]),
+                np.array([True, True, False, True, True, True]), 8,
+                dict(overflow=False, pairs=3))
+    if name == "sentinel_code_row":
+        # domain**k == 2**32: a valid row may carry the code invalid rows
+        # sort under; it must match valid right rows only.
+        lc = u32([_SENTINEL, 4, _SENTINEL, 0])
+        lv = np.array([True, True, False, True])
+        rc = u32([_SENTINEL, 4, 0, _SENTINEL, 7, 4])
+        rv = np.array([True, True, False, False, False, True])
+        return lc, lv, rc, rv, 16, dict(overflow=False, pairs=3)
+    if name == "random_overflowing":
+        lc = u32(rng.integers(0, 6, 32))
+        lv = rng.random(32) > 0.2
+        rc = u32(rng.integers(0, 6, 48))
+        rv = rng.random(48) > 0.2
+        return lc, lv, rc, rv, 64, dict(overflow=True, pairs=64)
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("case", [
+    "no_valid_left", "no_valid_right", "no_key_matches",
+    "one_left_row_overflows", "total_equals_cap", "duplicate_right_codes",
+    "one_left_row", "sentinel_code_row", "random_overflowing",
+])
+def test_join_row_codes_matches_per_slot_search(case):
+    lc, lv, rc, rv, out_cap, expect = _pair_case(case)
+    got = jax.jit(join_row_codes, static_argnums=4)(lc, lv, rc, rv, out_cap)
+    want = jax.jit(_join_row_codes_by_search, static_argnums=4)(
+        lc, lv, rc, rv, out_cap)
+    for name, g, w in zip(("li", "ri", "valid", "overflow"), got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    assert bool(got[3]) == expect["overflow"]
+    if expect["pairs"] is not None:
+        assert int(np.asarray(got[2]).sum()) == expect["pairs"]
+
+
+def _primitives(jaxpr, acc):
+    for eqn in jaxpr.eqns:
+        acc.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(x, jcore.ClosedJaxpr):
+                    _primitives(x.jaxpr, acc)
+                elif isinstance(x, jcore.Jaxpr):
+                    _primitives(x, acc)
+    return acc
+
+
+def test_join_row_codes_loops_only_in_key_searches():
+    # Each binary search is one loop (JAX traces its fixed trip count as
+    # ``scan``; a ``while`` would count the same): the two searches of the
+    # left keys in the sorted right keys, and none over the pair slots.
+    u32, b = jnp.zeros((32,), jnp.uint32), jnp.zeros((32,), jnp.bool_)
+    closed = jax.make_jaxpr(
+        lambda lc, lv, rc, rv: join_row_codes(lc, lv, rc, rv, 256)
+    )(u32, b, jnp.zeros((48,), jnp.uint32), jnp.zeros((48,), jnp.bool_))
+    prims = _primitives(closed.jaxpr, [])
+    assert prims.count("while") + prims.count("scan") <= 2, prims
